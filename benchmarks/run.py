@@ -17,6 +17,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def _time_call(fn, *args, warmup: int = 1, iters: int = 5) -> float:
     for _ in range(warmup):
@@ -342,7 +344,8 @@ def _run_shard_cell(n_dev: int, m: int, n: int, d: int, rounds: int,
 
     snippet = _SHARD_CELL_SNIPPET.format(rounds=rounds, m=m, n=n, d=d,
                                          engine=engine, dtype=dtype)
-    env = {**os.environ, "REPRO_FORCE_DEVICES": str(n_dev)}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "REPRO_FORCE_DEVICES": str(n_dev)}
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     try:
@@ -617,7 +620,8 @@ def gal_lm_tokens_benchmark(json_rows: list | None = None,
 
     snippet = _LM_TOKENS_SNIPPET.format(batch=batch, seq=seq, steps=steps,
                                         model_shards=model_shards)
-    env = {**os.environ, "REPRO_FORCE_DEVICES": str(model_shards)}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "REPRO_FORCE_DEVICES": str(model_shards)}
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     try:
@@ -753,6 +757,7 @@ def load_bench_json(path: str) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run a single table (table1..table6, fig4, table14)")

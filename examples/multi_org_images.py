@@ -21,9 +21,11 @@ from repro.data.partition import flatten_for_tabular, split_image_patches
 from repro.data.synthetic import make_patch_images, train_test_split
 from repro.metrics.metrics import accuracy
 from repro.models.zoo import ConvNet
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     key = jax.random.PRNGKey(0)
     ds = make_patch_images(rng, n=256, size=8, k=4, informative_center=True)

@@ -24,9 +24,11 @@ from repro.data.partition import split_features
 from repro.data.synthetic import make_regression, train_test_split
 from repro.metrics.metrics import mad
 from repro.models.zoo import KernelRidge, Linear, MLP, StumpBoost
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     key = jax.random.PRNGKey(0)
 

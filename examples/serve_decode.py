@@ -19,9 +19,11 @@ import jax.numpy as jnp
 from repro.configs import ALL_ARCHS, get_arch
 from repro.models import transformer as tfm
 from repro.train.steps import make_serve_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="zamba2-2.7b", choices=ALL_ARCHS)
     ap.add_argument("--batch", type=int, default=4)

@@ -32,9 +32,11 @@ from repro.checkpoint import artifact_info, load_lm_artifact, save_lm_artifact
 from repro.configs import get_arch
 from repro.core import gal_lm
 from repro.data.tokens import make_token_stream, token_batches
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=("smoke", "100m"), default="smoke")
     ap.add_argument("--rounds", type=int, default=3)

@@ -84,7 +84,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.losses import Loss, lq_loss
@@ -379,9 +378,43 @@ def _run_rounds(key, y_in, evals_in, broadcast, fit_orgs, *, loss, config,
     carry0 = (f, f_evals, key, active0, state0)
     sched_rows = (jnp.ones((config.rounds - t0, m), bool)
                   if member_sched is None else member_sched[t0:])
-    carry, outs = jax.lax.scan(round_step, carry0,
+    carry, outs = _scan_rounds(round_step, carry0,
                                (jnp.arange(t0, config.rounds), sched_rows))
     return outs, init, carry
+
+
+def _scan_rounds(step, carry, xs):
+    """``lax.scan(step, carry, xs)``, kept a loop when it has one round.
+
+    XLA inlines a loop of one trip into its caller, where the known
+    starting carry fuses into the round's math differently than inside the
+    loop; a one-round fit then differs from round 0 of a longer fit in the
+    last f32 bits. Resume and the contributivity counterfactuals promise
+    bitwise equality across such splits, so a single round runs in a
+    ``while_loop`` bounded by a value hidden behind an optimization barrier.
+    Longer fits stay a ``lax.scan``, whose trip count the roofline parser
+    reads from the HLO."""
+    length = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    if length != 1:
+        return jax.lax.scan(step, carry, xs)
+    at = lambda i: jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), xs)
+    out_shapes = jax.eval_shape(step, carry, at(0))[1]
+    ys = jax.tree_util.tree_map(
+        lambda s: jnp.zeros((length,) + s.shape, s.dtype), out_shapes)
+
+    def body(state):
+        i, c, ys = state
+        c, y = step(c, at(i))
+        ys = jax.tree_util.tree_map(
+            lambda b, v: jax.lax.dynamic_update_index_in_dim(b, v, i, 0),
+            ys, y)
+        return i + 1, c, ys
+
+    n = jax.lax.optimization_barrier(jnp.int32(length))
+    _, carry, ys = jax.lax.while_loop(lambda s: s[0] < n, body,
+                                      (jnp.int32(0), carry, ys))
+    return carry, ys
 
 
 def _dms_org_round(model, lloss, key_m, x_m, ext_m, heads_m, rhist, t,
@@ -1096,11 +1129,11 @@ def _shard_program(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
                 extras_specs]
     operands = [key0, y_dev, x_stack, org_ids, eval_stacks, sched_in,
                 ids_full, extras]
-    run_sharded = shard_map(
+    run_sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(out_specs, P(), carry_specs),
-        check_rep=False,
+        check_vma=False,
     )
     return {"jit": jax.jit(run_sharded), "operands": operands,
             "mesh": mesh, "dims": dims, "pad_to": pad_to,

@@ -1,20 +1,23 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On this CPU container kernels run with interpret=True (Python emulation of
-the kernel body); on TPU set REPRO_PALLAS_INTERPRET=0 to lower for real.
+The backend decides how a kernel runs: on TPU it always lowers through
+Mosaic; on any other backend (the CPU test container) the kernel body is
+emulated in interpret mode.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.residual_xent import residual_xent_kernel
 from repro.kernels import ref
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
 
 
 def residual_xent(logits: jnp.ndarray, labels: jnp.ndarray,
@@ -28,7 +31,7 @@ def residual_xent(logits: jnp.ndarray, labels: jnp.ndarray,
     flat = logits.reshape(-1, v)
     lab = labels.reshape(-1)
     if use_kernel:
-        out = residual_xent_kernel(flat, lab, interpret=INTERPRET)
+        out = residual_xent_kernel(flat, lab, interpret=_interpret())
     else:
         out = ref.residual_xent_ref(flat, lab)
     return out.reshape(*lead, v)
@@ -40,5 +43,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """GQA flash attention. q: (B,S,H,hd); k,v: (B,S,KV,hd) -> (B,S,H,hd)."""
     if use_kernel:
         return flash_attention_kernel(q, k, v, causal=causal, window=window,
-                                      interpret=INTERPRET)
+                                      interpret=_interpret())
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window)

@@ -30,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BT = 128   # token-block rows
 BV = 512   # vocab-block cols
@@ -45,22 +46,21 @@ def _stats_kernel(x_ref, m_ref, l_ref):
         l_ref[...] = jnp.zeros_like(l_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    m_prev = m_ref[...]
-    blk_max = jnp.max(x, axis=-1)
+    m_prev = m_ref[...]                                  # (BT, 1)
+    blk_max = jnp.max(x, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, blk_max)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(
-        jnp.exp(x - m_new[:, None]), axis=-1)
+        jnp.exp(x - m_new), axis=-1, keepdims=True)
     m_ref[...] = m_new
 
 
 def _resid_kernel(x_ref, lab_ref, m_ref, l_ref, out_ref):
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)
-    sm = jnp.exp(x - m_ref[...][:, None]) / jnp.maximum(
-        l_ref[...][:, None], 1e-30)
+    sm = jnp.exp(x - m_ref[...]) / jnp.maximum(l_ref[...], 1e-30)
     cols = j * BV + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    onehot = (lab_ref[...][:, None] == cols).astype(jnp.float32)
+    onehot = (lab_ref[...] == cols).astype(jnp.float32)
     out_ref[...] = (onehot - sm).astype(out_ref.dtype)
 
 
@@ -78,31 +78,35 @@ def residual_xent_kernel(logits: jnp.ndarray, labels: jnp.ndarray,
     vp = -(-v // BV) * BV
     x = jnp.pad(logits, ((0, tp - t), (0, vp - v)),
                 constant_values=NEG_INF)
-    lab = jnp.pad(labels.astype(jnp.int32), (0, tp - t), constant_values=-1)
+    lab = jnp.pad(labels.astype(jnp.int32), (0, tp - t),
+                  constant_values=-1).reshape(tp, 1)
     grid = (tp // BT, vp // BV)
+    # Per-row vectors travel as (BT, 1) blocks: Mosaic refuses 1-D blocks
+    # whose XLA tiling differs from its own. Vocab is the sequential
+    # reduction axis of the stats carry; token blocks are independent.
+    row = pl.BlockSpec((BT, 1), lambda i, j: (i, 0))
+    tile = pl.BlockSpec((BT, BV), lambda i, j: (i, j))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
     m, l = pl.pallas_call(
         _stats_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((BT, BV), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((BT,), lambda i, j: (i,)),
-                   pl.BlockSpec((BT,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((tp,), jnp.float32),
-                   jax.ShapeDtypeStruct((tp,), jnp.float32)],
+        in_specs=[tile],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((tp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((tp, 1), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
     )(x)
 
     out = pl.pallas_call(
         _resid_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((BT, BV), lambda i, j: (i, j)),
-            pl.BlockSpec((BT,), lambda i, j: (i,)),
-            pl.BlockSpec((BT,), lambda i, j: (i,)),
-            pl.BlockSpec((BT,), lambda i, j: (i,)),
-        ],
-        out_specs=pl.BlockSpec((BT, BV), lambda i, j: (i, j)),
+        in_specs=[tile, row, row, row],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((tp, vp), out_dtype),
+        compiler_params=params,
         interpret=interpret,
     )(x, lab, m, l)
     return out[:t, :v]

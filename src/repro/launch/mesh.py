@@ -34,8 +34,11 @@ def make_device_mesh(shape, axes):
     The one documented constructor for LM-arc meshes (serving, training,
     dry-run): pass ``production_mesh_spec()`` for the deployment target or a
     small shape like ``(2, 4)`` over ``("data", "model")`` for CPU sharding
-    tests (requires >= prod(shape) local devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    tests (requires >= prod(shape) local devices). Axes are ``Auto``: the
+    model code places activations through sharding constraints and lets
+    GSPMD propagate the rest, which explicit axes would refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def org_mesh_eligible(m: int, data_shards: int = 1) -> bool:

@@ -58,6 +58,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def measure_request_path(fn, steps: int):
     """Time a jitted request path two ways (all clocks monotonic):
@@ -352,6 +354,7 @@ def service_serve(args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--smoke", action="store_true")

@@ -1,8 +1,3 @@
-import os
-if os.environ.get("REPRO_FORCE_DEVICES"):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               f" --xla_force_host_platform_device_count="
-                               f"{os.environ['REPRO_FORCE_DEVICES']}")
 """Production training launcher: one GAL organization's local fit on the
 production mesh.
 
@@ -15,6 +10,9 @@ Examples:
   REPRO_FORCE_DEVICES=8 PYTHONPATH=src python -m repro.launch.train \
       --arch llama3-8b --smoke --mesh 2,4 --steps 4 --batch 8 --seq 64
 """
+from repro.utils.force_devices import apply_force_devices
+apply_force_devices()
+
 import argparse
 import time
 
@@ -22,8 +20,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -124,13 +124,12 @@ def rwkv_tmix_train(params, cfg: ModelConfig, x, x_prev_last=None):
             # layout (batch on data, heads on model) and runs fully LOCAL —
             # GSPMD propagation otherwise flips the stream batch-replicated
             # (measured 8 GiB unsharded f32 buffers per device; SS Perf)
-            from jax.experimental.shard_map import shard_map
             spec = P(bax, None, hax, None)
-            local = shard_map(
+            local = jax.shard_map(
                 lambda r_, k_, v_, w_, u_: _wkv_chunked(r_, k_, v_, w_, u_,
                                                         chunk, None, None),
                 mesh=mesh, in_specs=(spec, spec, spec, spec, P(hax, None)),
-                out_specs=spec, check_rep=False)
+                out_specs=spec, check_vma=False)
             ys = local(rf, kf, vf, w, u)
         else:
             ys = _wkv_chunked(rf, kf, vf, w, u, chunk, bax, hax)  # (B,S,H,hd)

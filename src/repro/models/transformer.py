@@ -152,41 +152,6 @@ def _group_of(n: int) -> int:
     return best
 
 
-@jax.custom_vjp
-def _stash_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-def _stash_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _stash_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-# optimization_barrier has no differentiation rule in this jax version; the
-# custom_vjp is the identity map with the barrier kept on both passes, so the
-# stash-dtype pinning in _scan_layers survives value_and_grad.
-_stash_barrier.defvjp(_stash_barrier_fwd, _stash_barrier_bwd)
-
-# It lacks a batching rule too, which the fused GAL engine needs to vmap one
-# architecture over org-stacked params. The barrier is elementwise-identity,
-# so batch dims pass straight through.
-try:
-    from jax._src.lax.lax import optimization_barrier_p as _barrier_p
-    from jax.interpreters import batching as _batching
-
-    if _barrier_p not in _batching.primitive_batchers:
-        def _barrier_batcher(batched_args, batch_dims):
-            outs = _barrier_p.bind(*batched_args)
-            return outs, batch_dims
-
-        _batching.primitive_batchers[_barrier_p] = _barrier_batcher
-except (ImportError, AttributeError):  # future jax: rules exist upstream
-    pass
-
-
 def _scan_layers(layers, body, x, aux0, remat: bool, group: bool = False):
     """Layer-stack execution. With remat: TWO-LEVEL (sqrt-L) checkpointing —
     an outer scan over G groups stashes only group-boundary activations; each
@@ -201,7 +166,7 @@ def _scan_layers(layers, body, x, aux0, remat: bool, group: bool = False):
         x, aux = carry
         # barrier pins the stash dtype: without it XLA hoists the backward's
         # first f32 convert of x into the per-layer stash, doubling it
-        x = _stash_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         x, a = fn(layer, x)
         return (x, aux + a), None
 

@@ -26,6 +26,7 @@ from typing import Dict
 import numpy as np
 
 from repro.configs.base import InputShape, ModelConfig
+from repro.roofline.hlo_stats import result_part
 
 
 @dataclass(frozen=True)
@@ -53,17 +54,12 @@ _COLLECTIVE_RE = re.compile(
 
 
 def _line_result_bytes(line: str) -> int:
-    """Sum the byte sizes of all shapes appearing before the op name
-    (the result shape(s) of the collective)."""
+    """Sum the byte sizes of the collective's result shape(s)."""
     head = line.split("=", 1)
     if len(head) != 2:
         return 0
-    # result shapes live between '=' and the op call; operands after '('.
-    rhs = head[1]
-    op_pos = rhs.find("(")
-    result_part = rhs[:op_pos] if op_pos >= 0 else rhs
     total = 0
-    for dt, dims in _SHAPE_RE.findall(result_part):
+    for dt, dims in _SHAPE_RE.findall(result_part(head[1])):
         n = 1
         if dims:
             for d in dims.split(","):

@@ -49,9 +49,20 @@ def _shape_bytes(text: str) -> int:
     return total
 
 
-def _result_part(rhs: str) -> str:
-    pos = rhs.find("(")
-    return rhs[:pos] if pos >= 0 else rhs
+def result_part(rhs: str) -> str:
+    """The result shape(s) of an instruction's right-hand side: the text
+    before the op's operand list, or the whole leading tuple when the
+    instruction returns one (a combined all-reduce does)."""
+    text = rhs.lstrip()
+    if not text.startswith("("):
+        pos = text.find("(")
+        return text[:pos] if pos >= 0 else text
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += {"(": 1, ")": -1}.get(ch, 0)
+        if depth == 0:
+            return text[:i + 1]
+    return text
 
 
 @dataclass
@@ -66,11 +77,11 @@ class Instruction:
 
     @property
     def result_bytes(self) -> int:
-        return _shape_bytes(_result_part(self.rhs))
+        return _shape_bytes(result_part(self.rhs))
 
     @property
     def result_dims(self):
-        m = _SHAPE_RE.search(_result_part(self.rhs))
+        m = _SHAPE_RE.search(result_part(self.rhs))
         if not m:
             return None
         return [int(d) for d in m.group(2).split(",") if d]

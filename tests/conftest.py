@@ -9,6 +9,11 @@ import pytest
 import jax
 
 
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: compiles production-size programs (tens of s)")
+
+
 @pytest.fixture
 def rng_np():
     return np.random.default_rng(0)

@@ -151,7 +151,8 @@ def _game(key, perm=None):
     # nonlinear target: linear orgs can't reach the float-noise floor, so
     # coalition values stay O(1) and relative comparisons mean something
     y = jnp.asarray(np.tanh(x @ beta) + 0.5 * np.sin(3.0 * x[:, 0])
-                    + 0.1 * rng.standard_normal(48).astype(np.float32))
+                    + 0.1 * rng.standard_normal(48).astype(np.float32)
+                    )[:, None]                          # (N, K=1) targets
     xs = split_features(jnp.asarray(x), M)
     from repro.models.zoo import Linear
     orgs = make_orgs(xs, Linear())
