@@ -98,6 +98,7 @@ from repro.launch.mesh import (grouped_mesh_eligible, make_org_mesh,
                                org_block_size, org_mesh_eligible)
 from repro.launch.sharding import org_replicated, org_stack_sharding
 from repro.optim.lbfgs import line_search
+from repro.utils import tracing
 
 
 def scan_compatible(orgs: Sequence[Any],
@@ -160,8 +161,10 @@ def _finalize(outs: Dict[str, Any], init: Dict[str, Any], masked: bool,
     which grows per round for fresh-fit orgs), trimmed like every other
     column on early stop."""
     params_stacked = outs.pop("params")           # stays on device
-    scalars, init = jax.device_get((outs, init))  # the ONE host sync
+    with tracing.span("sync"):
+        scalars, init = jax.device_get((outs, init))  # the ONE host sync
     n_valid = int(scalars["valid"].sum()) if masked else rounds
+    tracing.count("rounds", n_valid)
     history: Dict[str, List[float]] = {}
     for col, vals in scalars.items():
         if col in ("eta", "w", "valid"):
@@ -300,60 +303,67 @@ def _run_rounds(key, y_in, evals_in, broadcast, fit_orgs, *, loss, config,
         # everywhere), so an unmasked fit stays bit-identical to before
         member = member_row if have_sched else None
         f, f_evals, key, active, state = carry
-        key, k_round = jax.random.split(key)
-        # 1. pseudo-residual  2. privatized broadcast
-        residual = loss.residual(y_in, f)
-        r_wire = apply_privacy(
-            jax.random.fold_in(k_round, 13), residual, config.privacy,
-            alpha=config.privacy_alpha,
-            n_intervals=config.privacy_intervals,
-        )
-        if compress:
-            r_wire = r_wire.astype(jnp.bfloat16)
-        r_bcast = broadcast(r_wire)
-        if r_bcast.dtype != residual.dtype:
-            r_bcast = r_bcast.astype(residual.dtype)
-        # 3. parallel local fits over the org axis
-        state, params_out, preds, combine = fit_orgs(
-            k_round, r_bcast, t, state, active, member)
-        # 4. gradient assistance weights (masked over this round's live orgs)
-        if config.use_weights and m > 1:
-            w = fit_weights(
-                jax.random.fold_in(k_round, 29), residual, preds,
-                alice_loss, epochs=config.weight_epochs,
-                lr=config.weight_lr, weight_decay=config.weight_decay,
-                mask=member, org_ids=org_ids,
-                **(wfit_kwargs(preds, residual)
-                   if wfit_kwargs is not None else {}),
+        # 1. pseudo-residual
+        with tracing.scope("residual"):
+            key, k_round = jax.random.split(key)
+            residual = loss.residual(y_in, f)
+        # 2. privatized broadcast
+        with tracing.scope("broadcast"):
+            r_wire = apply_privacy(
+                jax.random.fold_in(k_round, 13), residual, config.privacy,
+                alpha=config.privacy_alpha,
+                n_intervals=config.privacy_intervals,
             )
-        else:
-            w = uniform_weights(m, mask=member)
-        direction = combine(w, None)
+            if compress:
+                r_wire = r_wire.astype(jnp.bfloat16)
+            r_bcast = broadcast(r_wire)
+            if r_bcast.dtype != residual.dtype:
+                r_bcast = r_bcast.astype(residual.dtype)
+        # 3. parallel local fits over the org axis
+        with tracing.scope("local_fit"):
+            state, params_out, preds, combine = fit_orgs(
+                k_round, r_bcast, t, state, active, member)
+        # 4. gradient assistance weights (masked over this round's live orgs)
+        with tracing.scope("weight_fit"):
+            if config.use_weights and m > 1:
+                w = fit_weights(
+                    jax.random.fold_in(k_round, 29), residual, preds,
+                    alice_loss, epochs=config.weight_epochs,
+                    lr=config.weight_lr, weight_decay=config.weight_decay,
+                    mask=member, org_ids=org_ids,
+                    **(wfit_kwargs(preds, residual)
+                       if wfit_kwargs is not None else {}),
+                )
+            else:
+                w = uniform_weights(m, mask=member)
+        with tracing.scope("combine"):
+            direction = combine(w, None)
 
         # 5. line-search eta   6. masked ensemble update
         # on a data-sharded mesh the loss value is global (psum'd) but its
         # AD gradient is shard-local; _grad_allreduce on eta restores the
         # global gradient the secant iteration needs
-        eta_in = ((lambda e: _grad_allreduce(e, eta_grad_axes))
-                  if eta_grad_axes else (lambda e: e))
-        eta = line_search(
-            lambda e: loss(y_in, f + eta_in(e) * direction),
-            method=config.eta_method, x0=config.eta0,
-        )
-        eta_eff = jnp.where(active, eta, 0.0) if masked else eta
-        f_new = f + eta_eff * direction
+        with tracing.scope("eta"):
+            eta_in = ((lambda e: _grad_allreduce(e, eta_grad_axes))
+                      if eta_grad_axes else (lambda e: e))
+            eta = line_search(
+                lambda e: loss(y_in, f + eta_in(e) * direction),
+                method=config.eta_method, x0=config.eta0,
+            )
+            eta_eff = jnp.where(active, eta, 0.0) if masked else eta
+            f_new = f + eta_eff * direction
 
-        outs = {"params": params_out, "eta": eta_eff, "w": w,
-                "valid": active, "train_loss": loss(y_in, f_new)}
-        new_evals = {}
-        for name, (_, y_e) in evals_in.items():
-            fe = f_evals[name] + eta_eff * combine(w, name)
-            new_evals[name] = fe
-            outs[f"{name}_loss"] = loss(y_e, fe)
-            for mname, metric_fn in (metrics or {}).items():
-                outs[f"{name}_{mname}"] = metric_fn(y_e, fe)
-        new_active = (active & (jnp.abs(eta) >= config.eta_stop_threshold)
-                      if masked else active)
+            outs = {"params": params_out, "eta": eta_eff, "w": w,
+                    "valid": active, "train_loss": loss(y_in, f_new)}
+            new_evals = {}
+            for name, (_, y_e) in evals_in.items():
+                fe = f_evals[name] + eta_eff * combine(w, name)
+                new_evals[name] = fe
+                outs[f"{name}_loss"] = loss(y_e, fe)
+                for mname, metric_fn in (metrics or {}).items():
+                    outs[f"{name}_{mname}"] = metric_fn(y_e, fe)
+            new_active = (active & (jnp.abs(eta) >= config.eta_stop_threshold)
+                          if masked else active)
         return (f_new, new_evals, key, new_active, state), outs
 
     if restore is None:
@@ -583,54 +593,62 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
     alice_loss = lq_loss(config.alice_q)
     masked = config.eta_stop_threshold > 0.0
 
-    mesh = None
-    if (not plan.homogeneous and not plan.has_dms
-            and grouped_mesh_eligible([g.size for g in groups])):
-        mesh = make_org_mesh(len(jax.devices()))
+    # staging: the orgs' slices stacked per group and placed, the operands
+    # of the round program
+    with tracing.span("stage"):
+        mesh = None
+        if (not plan.homogeneous and not plan.has_dms
+                and grouped_mesh_eligible([g.size for g in groups])):
+            mesh = make_org_mesh(len(jax.devices()))
 
-    index_groups = [g.indices for g in groups]
-    group_x, group_dims, group_pads = stack_groups(
-        [org.x_train for org in orgs], index_groups, mesh=mesh)
-    group_ids = [jnp.asarray(g.org_ids, jnp.uint32) for g in groups]
-    group_pos = [jnp.asarray(g.indices, jnp.int32) for g in groups]
-    inv_perm = jnp.asarray(plan.inverse_permutation, jnp.int32)
-    org_ids_all = jnp.asarray([org.index for org in orgs], jnp.uint32)
-    sched_np = None if membership is None else np.asarray(membership, bool)
-    sched_in = None if sched_np is None else jnp.asarray(sched_np)
+        index_groups = [g.indices for g in groups]
+        group_x, group_dims, group_pads = stack_groups(
+            [org.x_train for org in orgs], index_groups, mesh=mesh)
+        group_ids = [jnp.asarray(g.org_ids, jnp.uint32) for g in groups]
+        group_pos = [jnp.asarray(g.indices, jnp.int32) for g in groups]
+        inv_perm = jnp.asarray(plan.inverse_permutation, jnp.int32)
+        org_ids_all = jnp.asarray([org.index for org in orgs], jnp.uint32)
+        sched_np = (None if membership is None
+                    else np.asarray(membership, bool))
+        sched_in = None if sched_np is None else jnp.asarray(sched_np)
 
-    y_in = y if mesh is None else jax.device_put(y, org_replicated(mesh))
-    eval_stacks = {}
-    if eval_sets:
-        for name, (xs_e, y_e) in eval_sets.items():
-            stacks_e, _, _ = stack_groups(list(xs_e), index_groups,
-                                          pad_tos=group_pads, mesh=mesh)
-            y_e_in = (y_e if mesh is None
-                      else jax.device_put(y_e, org_replicated(mesh)))
-            eval_stacks[name] = (tuple(stacks_e), y_e_in)
+        y_in = y if mesh is None else jax.device_put(y, org_replicated(mesh))
+        eval_stacks = {}
+        if eval_sets:
+            for name, (xs_e, y_e) in eval_sets.items():
+                stacks_e, _, _ = stack_groups(list(xs_e), index_groups,
+                                              pad_tos=group_pads, mesh=mesh)
+                y_e_in = (y_e if mesh is None
+                          else jax.device_put(y_e, org_replicated(mesh)))
+                eval_stacks[name] = (tuple(stacks_e), y_e_in)
 
-    t0 = 0
-    key0 = rng
-    resume_in = None
-    if resume is not None:
-        t0 = int(resume["t_next"])
-        key0 = jnp.asarray(resume["key"])
-        resume_in = {
-            "f": jnp.asarray(resume["f"]),
-            "f_evals": {nm: jnp.asarray(v)
-                        for nm, v in resume.get("f_evals", {}).items()},
-            "active": jnp.asarray(resume["active"]),
-            "state": _pad_rounds(resume.get("state", {}) or {}, groups,
-                                 t0, config.rounds),
-        }
+        t0 = 0
+        key0 = rng
+        resume_in = None
+        if resume is not None:
+            t0 = int(resume["t_next"])
+            key0 = jnp.asarray(resume["key"])
+            resume_in = {
+                "f": jnp.asarray(resume["f"]),
+                "f_evals": {nm: jnp.asarray(v)
+                            for nm, v in resume.get("f_evals", {}).items()},
+                "active": jnp.asarray(resume["active"]),
+                "state": _pad_rounds(resume.get("state", {}) or {}, groups,
+                                     t0, config.rounds),
+            }
+            if mesh is not None:
+                resume_in = jax.tree_util.tree_map(
+                    lambda a: jax.device_put(a, org_replicated(mesh)),
+                    resume_in)
         if mesh is not None:
-            resume_in = jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, org_replicated(mesh)), resume_in)
-    if mesh is not None:
-        org_ids_all = jax.device_put(org_ids_all, org_replicated(mesh))
-        if sched_in is not None:
-            sched_in = jax.device_put(sched_in, org_replicated(mesh))
+            org_ids_all = jax.device_put(org_ids_all, org_replicated(mesh))
+            if sched_in is not None:
+                sched_in = jax.device_put(sched_in, org_replicated(mesh))
 
-    def run(key, y_dev, xg_in, evals_in, res_in, sched_dev, ids_dev):
+    # the round program (its XLA module is ``jit_gal_rounds``)
+    def gal_rounds(key, y_dev, xg_in, evals_in, res_in, sched_dev,
+                   ids_dev):
+        tracing.count("round_traces")     # runs only while JAX traces it
         # DMS carry: one shared (T, N, K) residual-history buffer plus each
         # DMS group's extractor stack and (M_g, T, ...) head buffers. The
         # extractor inits replicate the reference exactly: round 0's
@@ -778,9 +796,10 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
                            restore=restore, member_sched=sched_dev,
                            org_ids=ids_dev)
 
-    outs, init, carry = jax.jit(run)(key0, y_in, tuple(group_x),
-                                     eval_stacks, resume_in, sched_in,
-                                     org_ids_all)
+    with tracing.span("launch"):
+        outs, init, carry = jax.jit(gal_rounds)(
+            key0, y_in, tuple(group_x), eval_stacks, resume_in, sched_in,
+            org_ids_all)
     state_final = carry[4]
     dms_flags = [False] * m
     for g in groups:
@@ -798,14 +817,15 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
                                                    resid_dtype_bytes=rb)
         bcast_b, gather_b = bcast_l[t0:], gather_l[t0:]
     single = len(groups) == 1 and not plan.has_dms
-    out = _finalize(outs, init, masked, config.rounds - t0,
-                    dims=group_dims[0] if single else None,
-                    pad_to=group_pads[0] if single else None,
-                    comm={"comm_broadcast_bytes": bcast_b,
-                          "comm_gather_bytes": gather_b,
-                          "model_memories": gal_model_memories(
-                              config.rounds, dms_flags,
-                              membership=sched_np)[t0:]})
+    with tracing.span("finalize"):
+        out = _finalize(outs, init, masked, config.rounds - t0,
+                        dims=group_dims[0] if single else None,
+                        pad_to=group_pads[0] if single else None,
+                        comm={"comm_broadcast_bytes": bcast_b,
+                              "comm_gather_bytes": gather_b,
+                              "model_memories": gal_model_memories(
+                                  config.rounds, dms_flags,
+                                  membership=sched_np)[t0:]})
     if sched_np is not None:
         # executed rows only (early-stop trimmed), host bools in org order
         out["membership"] = sched_np[t0:t0 + len(out["etas"])].tolist()
@@ -975,7 +995,9 @@ def _shard_program(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
             "f_evals": {name: P() for name in eval_stacks},
             "active": P()}
 
-    def run(key, y_in, x_in, ids_in, evals_in, sched_dev, ids_all, extra):
+    def gal_rounds(key, y_in, x_in, ids_in, evals_in, sched_dev, ids_all,
+                   extra):
+        tracing.count("round_traces")     # runs only while JAX traces it
         pos = jax.lax.axis_index("org")
 
         def broadcast(r_wire):
@@ -1130,7 +1152,7 @@ def _shard_program(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
     operands = [key0, y_dev, x_stack, org_ids, eval_stacks, sched_in,
                 ids_full, extras]
     run_sharded = jax.shard_map(
-        run, mesh=mesh,
+        gal_rounds, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(out_specs, P(), carry_specs),
         check_vma=False,
@@ -1180,9 +1202,11 @@ def fit_shard(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray, loss: Loss,
     have static shapes — but its assistance weight is exactly 0.0, so its
     psum contribution is exact zeros and the recorded per-round wire
     ledger counts only the live orgs."""
-    prog = _shard_program(rng, orgs, y, loss, config, eval_sets, metrics,
-                          resume, membership)
-    outs, init, carry = prog["jit"](*prog["operands"])
+    with tracing.span("stage"):
+        prog = _shard_program(rng, orgs, y, loss, config, eval_sets,
+                              metrics, resume, membership)
+    with tracing.span("launch"):
+        outs, init, carry = prog["jit"](*prog["operands"])
     # per-round ledger of the collectives above, from the (static) operand
     # shapes — exact ints, Table-14 convention (Alice already holds her
     # residual copy; all M orgs ship fitted values for the train AND eval
@@ -1199,13 +1223,14 @@ def fit_shard(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray, loss: Loss,
         bcast_l, gather_l = membership_comm_ledger(sched_np, n, k, eval_ns,
                                                    resid_dtype_bytes=rb)
         bcast_b, gather_b = bcast_l[t0:], gather_l[t0:]
-    out = _finalize(outs, init, prog["masked"], config.rounds - t0,
-                    prog["dims"], prog["pad_to"],
-                    comm={"comm_broadcast_bytes": bcast_b,
-                          "comm_gather_bytes": gather_b,
-                          "model_memories": gal_model_memories(
-                              config.rounds, [False] * m,
-                              membership=sched_np)[t0:]})
+    with tracing.span("finalize"):
+        out = _finalize(outs, init, prog["masked"], config.rounds - t0,
+                        prog["dims"], prog["pad_to"],
+                        comm={"comm_broadcast_bytes": bcast_b,
+                              "comm_gather_bytes": gather_b,
+                              "model_memories": gal_model_memories(
+                                  config.rounds, [False] * m,
+                                  membership=sched_np)[t0:]})
     if sched_np is not None:
         out["membership"] = sched_np[t0:t0 + len(out["etas"])].tolist()
     out["resume"] = {"t_next": config.rounds, "f": carry[0],

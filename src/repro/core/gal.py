@@ -52,7 +52,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +69,7 @@ from repro.core.weights import fit_weights, uniform_weights
 from repro.launch.mesh import org_mesh_eligible
 from repro.metrics.metrics import METRICS, get_metric
 from repro.optim.lbfgs import line_search
+from repro.utils import tracing
 
 _COMPILED_ENGINES = ("scan", "shard", "grouped")
 
@@ -353,6 +354,35 @@ def fit(rng: jax.Array, orgs: List[Organization], y: jnp.ndarray, loss: Loss,
     partitions the orgs into homogeneous groups or names the reason the
     compiled engines cannot run; forcing a compiled engine on an
     uncompilable set raises that reason verbatim."""
+    with tracing.fit_span("fit"):
+        with tracing.span("plan"):
+            p = _plan_fit(orgs, y, loss, config, eval_sets, metric_fn,
+                          metrics, resume_from, membership)
+        if p.python:
+            return _fit_python(rng, orgs, y, loss, config, eval_sets,
+                               p.metric_map, membership=p.sched)
+        result = _dispatch_compiled(rng, orgs, y, loss, config, eval_sets,
+                                    p.metric_map, p.plan, p.resume_eng,
+                                    p.sched)
+        if p.resume_art is not None:
+            result = _stitch_resume(p.resume_art, result, p.plan,
+                                    growth=p.growth)
+        return result
+
+
+class _FitPlan(NamedTuple):
+    metric_map: Dict[str, Callable]
+    plan: ExecutionPlan
+    sched: Any                  # resolved membership schedule, or None
+    resume_art: Any             # the artifact resumed from, or None
+    resume_eng: Any             # its resume carry for the engines, or None
+    growth: Any
+    python: bool                # the Python reference loop runs
+
+
+def _plan_fit(orgs, y, loss, config, eval_sets, metric_fn, metrics,
+              resume_from, membership) -> _FitPlan:
+    """``fit``'s planning, validation and resume preparation."""
     if config.engine not in ("auto", "python") + _COMPILED_ENGINES:
         raise ValueError(f"unknown engine {config.engine!r}")
     if config.residual_dtype not in ("float32", "fp32", "bf16", "bfloat16"):
@@ -450,17 +480,8 @@ def fit(rng: jax.Array, orgs: List[Organization], y: jnp.ndarray, loss: Loss,
             if why:
                 raise ValueError(
                     f"cannot run these organizations on ANY engine: {why}")
-        return _fit_python(rng, orgs, y, loss, config, eval_sets,
-                           metric_map, membership=sched)
-    if config.engine == "python":
-        return _fit_python(rng, orgs, y, loss, config, eval_sets,
-                           metric_map, membership=sched)
-
-    result = _dispatch_compiled(rng, orgs, y, loss, config, eval_sets,
-                                metric_map, plan, resume_eng, sched)
-    if resume_art is not None:
-        result = _stitch_resume(resume_art, result, plan, growth=growth)
-    return result
+    return _FitPlan(metric_map, plan, sched, resume_art, resume_eng, growth,
+                    python=not plan.compiled or config.engine == "python")
 
 
 def _resume_schedule(art: GALResult, resume_eng: Dict[str, Any], growth,
@@ -557,7 +578,8 @@ def _fit_fast(engine_fn, name, plan, rng, orgs, y, loss, config, eval_sets,
                     "single-host fused path")
         out = engine_fn(rng, orgs, y, loss, config, eval_sets, metrics,
                         plan=plan, resume=resume, membership=membership)
-    return _fast_result(orgs, y, loss, out, name, plan, config)
+    with tracing.span("finalize"):
+        return _fast_result(orgs, y, loss, out, name, plan, config)
 
 
 def _fast_result(orgs, y, loss, out, engine: str, plan: ExecutionPlan,
@@ -961,4 +983,5 @@ def _fit_python(rng, orgs, y, loss, config, eval_sets, metrics,
     if membership is not None:
         result.membership = np.asarray(
             membership[:len(result.etas)], bool).tolist()
+    tracing.count("rounds", len(result.etas))
     return result

@@ -57,6 +57,7 @@ from repro.kernels.ops import residual_xent
 from repro.models import transformer as tfm
 from repro.optim.lbfgs import line_search
 from repro.train.steps import make_train_step, run_local_steps
+from repro.utils import tracing
 
 # the step-4 weight fit budget at LM scale: the (M, B*S, V) pred stack is
 # the dominant operand, so fewer Adam epochs than the tabular default
@@ -244,36 +245,42 @@ def fit_lm(rng: jax.Array, orgs: List[LMOrganization], tokens: jnp.ndarray,
     ``store_round_params=False`` drops the per-round param stack (halves
     device memory; ``predict(rounds=t)`` then needs a re-fit).
     """
-    if engine not in _ENGINES:
-        raise ValueError(f"unknown engine {engine!r} (one of {_ENGINES})")
-    plan = plan_lm_orgs(orgs)
-    if not plan.compiled:
-        raise ValueError(f"cannot fit this org set: {plan.reason}")
-    vocabs = {int(org.cfg.vocab) for org in orgs}
-    if len(vocabs) != 1:
-        raise ValueError(
-            f"all organizations must share Alice's vocab (the residual "
-            f"broadcast is one (B, S, V) tensor); got vocabs "
-            f"{sorted(vocabs)}")
-    if engine == "scan" and plan.n_groups != 1:
-        raise ValueError(
-            "engine='scan' needs one shared (architecture config, lr) "
-            "group across orgs — use engine='grouped' for a mixed set: "
-            + plan.describe())
-    spec = _make_fit_spec(orgs, labels, local_steps, eta_method,
-                          use_weights, use_kernel, eta_stop_threshold)
-    resume = None
-    if resume_from is not None:
+    with tracing.fit_span("fit_lm"):
+        with tracing.span("plan"):
+            if engine not in _ENGINES:
+                raise ValueError(
+                    f"unknown engine {engine!r} (one of {_ENGINES})")
+            plan = plan_lm_orgs(orgs)
+            if not plan.compiled:
+                raise ValueError(f"cannot fit this org set: {plan.reason}")
+            vocabs = {int(org.cfg.vocab) for org in orgs}
+            if len(vocabs) != 1:
+                raise ValueError(
+                    f"all organizations must share Alice's vocab (the "
+                    f"residual broadcast is one (B, S, V) tensor); got "
+                    f"vocabs {sorted(vocabs)}")
+            if engine == "scan" and plan.n_groups != 1:
+                raise ValueError(
+                    "engine='scan' needs one shared (architecture config, "
+                    "lr) group across orgs — use engine='grouped' for a "
+                    "mixed set: " + plan.describe())
+            spec = _make_fit_spec(orgs, labels, local_steps, eta_method,
+                                  use_weights, use_kernel,
+                                  eta_stop_threshold)
+            resume = None
+            if resume_from is not None:
+                if engine == "python":
+                    raise ValueError(
+                        "resume_from= needs a compiled engine (the python "
+                        "reference loop has no resume carry)")
+                resume = _prepare_lm_resume(resume_from, plan, spec, rounds)
         if engine == "python":
-            raise ValueError("resume_from= needs a compiled engine (the "
-                             "python reference loop has no resume carry)")
-        resume = _prepare_lm_resume(resume_from, plan, spec, rounds)
-    if engine == "python":
-        return _fit_lm_python(rng, orgs, plan, tokens, labels, rounds, spec)
-    label = engine if engine != "auto" else (
-        "scan" if plan.n_groups == 1 else "grouped")
-    return _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec,
-                           label, store_round_params, resume)
+            return _fit_lm_python(rng, orgs, plan, tokens, labels, rounds,
+                                  spec)
+        label = engine if engine != "auto" else (
+            "scan" if plan.n_groups == 1 else "grouped")
+        return _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec,
+                               label, store_round_params, resume)
 
 
 def _prepare_lm_resume(resume_from: Any, plan: ExecutionPlan,
@@ -330,11 +337,7 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
     b, s = labels.shape
     vocab = spec["vocab"]
     xent = CrossEntropyLoss()
-    y1 = jax.nn.one_hot(labels.reshape(-1), vocab)
-    f0 = xent.init_prediction(y1)
     groups = plan.groups
-    views = [jnp.stack([orgs[i].view_fn(tokens) for i in g.indices])
-             for g in groups]
     vsteps = [jax.vmap(orgs[g.indices[0]]._train_step,
                        in_axes=(0, 0, {"tokens": 0, "residual": None}))
               for g in groups]
@@ -346,67 +349,85 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
     inv = tuple(plan.inverse_permutation)
     permuted = inv != tuple(range(m))
 
-    if resume is None:
-        t0 = 0
-        params0 = tuple(_stack_org_trees([orgs[i].params for i in g.indices])
-                        for g in groups)
-        opts0 = tuple(_stack_org_trees([orgs[i].opt_state
-                                        for i in g.indices]) for g in groups)
-        f_init = jnp.broadcast_to(jnp.reshape(f0, (1, vocab)),
-                                  (b * s, vocab)).astype(jnp.float32)
-        active0 = jnp.asarray(True)
-    else:
-        t0 = resume["t_next"]
-        params0, opts0 = resume["params"], resume["opts"]
-        f_init = jnp.asarray(resume["f"])
-        active0 = jnp.asarray(resume["active"])
+    with tracing.span("stage"):
+        y1 = jax.nn.one_hot(labels.reshape(-1), vocab)
+        f0 = xent.init_prediction(y1)
+        views = [jnp.stack([orgs[i].view_fn(tokens) for i in g.indices])
+                 for g in groups]
+        if resume is None:
+            t0 = 0
+            params0 = tuple(_stack_org_trees([orgs[i].params
+                                              for i in g.indices])
+                            for g in groups)
+            opts0 = tuple(_stack_org_trees([orgs[i].opt_state
+                                            for i in g.indices])
+                          for g in groups)
+            f_init = jnp.broadcast_to(jnp.reshape(f0, (1, vocab)),
+                                      (b * s, vocab)).astype(jnp.float32)
+            active0 = jnp.asarray(True)
+        else:
+            t0 = resume["t_next"]
+            params0, opts0 = resume["params"], resume["opts"]
+            f_init = jnp.asarray(resume["f"])
+            active0 = jnp.asarray(resume["active"])
 
-    def run(key, y1_in, labels_in, params_in, opts_in, f_in, active_in):
+    # the round program (its XLA module is ``jit_gal_lm_rounds``)
+    def gal_lm_rounds(key, y1_in, labels_in, params_in, opts_in, f_in,
+                      active_in):
+        tracing.count("round_traces")     # runs only while JAX traces it
+
         def round_step(carry, t):
             params_l, opts_l, f, active = carry
-            k_round = jax.random.fold_in(key, t)
-            residual = compute_residual(
-                labels_in, f.reshape(b, s, vocab), use_kernel=use_kernel)
-            new_params, new_opts, preds_g = [], [], []
-            for g in range(len(groups)):
-                p, o, _ = run_local_steps(
-                    vsteps[g], params_l[g], opts_l[g],
-                    {"tokens": views[g], "residual": residual}, local_steps)
-                pred = jax.vmap(
-                    lambda pp, vv, cfg=cfgs[g]: tfm.apply(pp, cfg, vv)[0]
-                )(p, views[g])
-                preds_g.append(
-                    pred.astype(jnp.float32).reshape(sizes[g], b * s, vocab))
-                new_params.append(p)
-                new_opts.append(o)
-            preds = jnp.concatenate(preds_g, axis=0)
-            if permuted:                        # back to original org order
-                preds = preds[jnp.asarray(inv)]
-            if use_weights and m > 1:
-                w = fit_weights(jax.random.fold_in(k_round, 29),
-                                residual.reshape(b * s, vocab), preds,
-                                _l2, epochs=WEIGHT_EPOCHS)
-            else:
-                w = uniform_weights(m)
-            direction = jnp.einsum("m,mnk->nk", w, preds)
-            eta = line_search(lambda e: xent(y1_in, f + e * direction),
-                              method=eta_method, x0=1.0)
-            eta_eff = jnp.where(active, eta, 0.0)
-            f_new = f + eta_eff * direction
-            if thr > 0.0:
-                # early stop: freeze org state on inactive rounds so the
-                # final carry equals the python loop's break semantics
-                frz = lambda new, old: jax.tree_util.tree_map(   # noqa: E731
-                    lambda a, c: jnp.where(active, a, c), new, old)
-                new_params = [frz(p, q) for p, q in zip(new_params,
-                                                        params_l)]
-                new_opts = [frz(o, q) for o, q in zip(new_opts, opts_l)]
-                new_active = active & (jnp.abs(eta) >= thr)
-            else:
-                new_active = active
-            outs = {"eta": eta_eff,
-                    "w": jnp.where(active, w, jnp.zeros_like(w)),
-                    "xent": xent(y1_in, f_new), "valid": active}
+            with tracing.scope("residual"):
+                k_round = jax.random.fold_in(key, t)
+                residual = compute_residual(
+                    labels_in, f.reshape(b, s, vocab), use_kernel=use_kernel)
+            with tracing.scope("local_fit"):
+                new_params, new_opts, preds_g = [], [], []
+                for g in range(len(groups)):
+                    p, o, _ = run_local_steps(
+                        vsteps[g], params_l[g], opts_l[g],
+                        {"tokens": views[g], "residual": residual},
+                        local_steps)
+                    pred = jax.vmap(
+                        lambda pp, vv, cfg=cfgs[g]: tfm.apply(pp, cfg, vv)[0]
+                    )(p, views[g])
+                    preds_g.append(pred.astype(jnp.float32).reshape(
+                        sizes[g], b * s, vocab))
+                    new_params.append(p)
+                    new_opts.append(o)
+                preds = jnp.concatenate(preds_g, axis=0)
+                if permuted:                    # back to original org order
+                    preds = preds[jnp.asarray(inv)]
+            with tracing.scope("weight_fit"):
+                if use_weights and m > 1:
+                    w = fit_weights(jax.random.fold_in(k_round, 29),
+                                    residual.reshape(b * s, vocab), preds,
+                                    _l2, epochs=WEIGHT_EPOCHS)
+                else:
+                    w = uniform_weights(m)
+            with tracing.scope("combine"):
+                direction = jnp.einsum("m,mnk->nk", w, preds)
+            with tracing.scope("eta"):
+                eta = line_search(lambda e: xent(y1_in, f + e * direction),
+                                  method=eta_method, x0=1.0)
+                eta_eff = jnp.where(active, eta, 0.0)
+                f_new = f + eta_eff * direction
+                if thr > 0.0:
+                    # early stop: freeze org state on inactive rounds so the
+                    # final carry equals the python loop's break semantics
+                    def frz(new, old):
+                        return jax.tree_util.tree_map(
+                            lambda a, c: jnp.where(active, a, c), new, old)
+                    new_params = [frz(p, q) for p, q in zip(new_params,
+                                                            params_l)]
+                    new_opts = [frz(o, q) for o, q in zip(new_opts, opts_l)]
+                    new_active = active & (jnp.abs(eta) >= thr)
+                else:
+                    new_active = active
+                outs = {"eta": eta_eff,
+                        "w": jnp.where(active, w, jnp.zeros_like(w)),
+                        "xent": xent(y1_in, f_new), "valid": active}
             if store_round_params:
                 outs["params"] = tuple(new_params)
             return (tuple(new_params), tuple(new_opts), f_new,
@@ -418,19 +439,23 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
         outs["xent0"] = xent(y1_in, f_in)
         return params, opts, f, active, outs
 
-    params, opts, f_fin, active_fin, outs = jax.jit(run)(
-        rng, y1, labels, params0, opts0, f_init, active0)
-    round_params = outs.pop("params", None)
-    scalars = jax.device_get(outs)                # the ONE host sync
-    valid = np.asarray(scalars["valid"], bool)
-    n_exec = int(valid.sum())                     # rounds actually executed
+    with tracing.span("launch"):
+        params, opts, f_fin, active_fin, outs = jax.jit(gal_lm_rounds)(
+            rng, y1, labels, params0, opts0, f_init, active0)
+    with tracing.span("finalize"):
+        round_params = outs.pop("params", None)
+        with tracing.span("sync"):
+            scalars = jax.device_get(outs)        # the ONE host sync
+        valid = np.asarray(scalars["valid"], bool)
+        n_exec = int(valid.sum())                 # rounds actually executed
+        tracing.count("rounds", n_exec)
 
-    for g, group in enumerate(groups):            # write back evolved state
-        for j, i in enumerate(group.indices):
-            orgs[i].params = jax.tree_util.tree_map(
-                lambda l, j=j: l[j], params[g])
-            orgs[i].opt_state = jax.tree_util.tree_map(
-                lambda l, j=j: l[j], opts[g])
+        for g, group in enumerate(groups):        # write back evolved state
+            for j, i in enumerate(group.indices):
+                orgs[i].params = jax.tree_util.tree_map(
+                    lambda l, j=j: l[j], params[g])
+                orgs[i].opt_state = jax.tree_util.tree_map(
+                    lambda l, j=j: l[j], opts[g])
 
     result = GALLMResult(orgs=orgs, f0=f0, engine=label, plan=plan,
                          fit_spec=spec)
@@ -511,6 +536,7 @@ def _fit_lm_python(rng, orgs, plan, tokens, labels, rounds,
             break
 
     etas_h, xents_h = jax.device_get((etas_d, xents))
+    tracing.count("rounds", len(etas_h))
     result.etas = [float(e) for e in etas_h]
     result.weights = ws
     result.history["train_xent"] = [float(v) for v in xents_h]
