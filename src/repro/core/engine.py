@@ -77,6 +77,8 @@ frozen) and trimmed from the returned history on the host side.
 """
 from __future__ import annotations
 
+import threading
+from collections import OrderedDict
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -145,6 +147,46 @@ def shard_eligible(orgs: Sequence[Any],
     whenever it holds."""
     return (scan_compatible(orgs, eval_sets)
             and org_mesh_eligible(len(orgs), data_shards))
+
+
+# The round programs built so far, least recently used first. A key is the
+# builder and its arguments, the program's signature: a builder's body
+# reads nothing else, so no device array of any fit is kept here.
+_ROUND_PROGRAMS_MAX = 16
+_round_programs: "OrderedDict[tuple, Callable]" = OrderedDict()
+_round_programs_lock = threading.Lock()
+
+
+def round_program(build: Callable[..., Callable], *signature) -> Callable:
+    """``jax.jit(build(*signature))``, built once per signature.
+
+    A fit whose signature equals an earlier fit's gets the jitted program
+    that fit built, so the program is neither traced, lowered nor loaded
+    again; JAX itself keys that program on the shapes, dtypes and
+    placements of its arguments. ``build`` is a module-level function
+    whose program reads its arguments and nothing else of the fit. The
+    store keeps ``_ROUND_PROGRAMS_MAX`` programs and drops the least
+    recently used; an unhashable signature gets a program that is not
+    kept."""
+    key = (build,) + signature
+    try:
+        hash(key)
+    except TypeError:
+        return jax.jit(build(*signature))
+    with _round_programs_lock:
+        program = _round_programs.pop(key, None)
+        if program is None:
+            program = jax.jit(build(*signature))    # lazy: nothing traced
+        _round_programs[key] = program
+        if len(_round_programs) > _ROUND_PROGRAMS_MAX:
+            _round_programs.popitem(last=False)
+    return program
+
+
+def clear_round_programs() -> None:
+    """Empty the store: the next fit of every signature builds anew."""
+    with _round_programs_lock:
+        _round_programs.clear()
 
 
 def _finalize(outs: Dict[str, Any], init: Dict[str, Any], masked: bool,
@@ -556,6 +598,17 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
     org-sharded along an "org" mesh axis and GSPMD partitions every
     group's fits across the devices.
 
+    A fit reuses the jitted round program of an earlier fit with an equal
+    signature (``round_program``), which then is neither traced, lowered
+    nor loaded again. The signature is the plan (each group's model, local
+    loss, noise sigma and DMS flag by value, its org positions and ids),
+    the frozen ``GALConfig``, the loss, the metric callables by identity
+    and the first round ``t0``; sizes, dtypes and placements are read from
+    the arguments, and every array of the fit is an argument. A model or
+    loss enters by value where it is a frozen dataclass (the zoo's are)
+    and by identity otherwise, so one changed in place after a fit keeps
+    its key: change it by making a new one.
+
     Returns a dict with host lists ``etas`` / ``weights``, the ``history``
     dict (losses/metrics as floats, the simulated per-round communication
     and model-memory ledgers as exact ints), device-side per-group stacked
@@ -590,7 +643,6 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
     groups = plan.groups
     m = len(orgs)
     n, k = y.shape[0], y.shape[-1]
-    alice_loss = lq_loss(config.alice_q)
     masked = config.eta_stop_threshold > 0.0
 
     # staging: the orgs' slices stacked per group and placed, the operands
@@ -604,9 +656,6 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
         index_groups = [g.indices for g in groups]
         group_x, group_dims, group_pads = stack_groups(
             [org.x_train for org in orgs], index_groups, mesh=mesh)
-        group_ids = [jnp.asarray(g.org_ids, jnp.uint32) for g in groups]
-        group_pos = [jnp.asarray(g.indices, jnp.int32) for g in groups]
-        inv_perm = jnp.asarray(plan.inverse_permutation, jnp.int32)
         org_ids_all = jnp.asarray([org.index for org in orgs], jnp.uint32)
         sched_np = (None if membership is None
                     else np.asarray(membership, bool))
@@ -645,10 +694,85 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
             if sched_in is not None:
                 sched_in = jax.device_put(sched_in, org_replicated(mesh))
 
+    with tracing.span("launch"):
+        gal_rounds = round_program(_grouped_rounds, plan, config, loss,
+                                   tuple((metrics or {}).items()), t0)
+        outs, init, carry = gal_rounds(
+            key0, y_in, tuple(group_x), eval_stacks, resume_in, sched_in,
+            org_ids_all)
+    state_final = carry[4]
+    dms_flags = [False] * m
+    for g in groups:
+        for i in g.indices:
+            dms_flags[i] = g.dms
+    eval_ns = [int(y_e.shape[0])
+               for (_, y_e) in (eval_sets or {}).values()]
+    rb = _resid_wire_bytes(config)
+    if sched_np is None:
+        bcast_b, gather_b = gal_round_bytes(n, k, m, eval_ns,
+                                            resid_dtype_bytes=rb)
+    else:
+        from repro.core.membership import membership_comm_ledger
+        bcast_l, gather_l = membership_comm_ledger(sched_np, n, k, eval_ns,
+                                                   resid_dtype_bytes=rb)
+        bcast_b, gather_b = bcast_l[t0:], gather_l[t0:]
+    single = len(groups) == 1 and not plan.has_dms
+    with tracing.span("finalize"):
+        out = _finalize(outs, init, masked, config.rounds - t0,
+                        dims=group_dims[0] if single else None,
+                        pad_to=group_pads[0] if single else None,
+                        comm={"comm_broadcast_bytes": bcast_b,
+                              "comm_gather_bytes": gather_b,
+                              "model_memories": gal_model_memories(
+                                  config.rounds, dms_flags,
+                                  membership=sched_np)[t0:]})
+    if sched_np is not None:
+        # executed rows only (early-stop trimmed), host bools in org order
+        out["membership"] = sched_np[t0:t0 + len(out["etas"])].tolist()
+    group_params = list(out["params"])            # tuple trimmed by _finalize
+    for gi, g in enumerate(groups):
+        if g.dms:
+            # the final carry state IS the fitted DMS ensemble: the shared
+            # extractor after the last live round plus every round's head
+            group_params[gi] = state_final[f"g{gi}"]
+    out["params"] = group_params[0] if single else None
+    out["group_params"] = group_params
+    out["group_dims"] = group_dims
+    out["group_pads"] = group_pads
+    out["plan"] = plan
+    out["mesh_devices"] = 0 if mesh is None else len(jax.devices())
+    # the final round-scan carry, verbatim: what save_artifact persists and
+    # a later fit(resume_from=...) restores. The key has been split once
+    # per scanned round (masked rounds included), so resuming continues
+    # the exact per-round draw chain of an uninterrupted longer fit.
+    out["resume"] = {"t_next": config.rounds, "f": carry[0],
+                     "f_evals": carry[1], "key": carry[2],
+                     "active": carry[3], "state": state_final}
+    return out
+
+
+def _grouped_rounds(plan: ExecutionPlan, config: Any, loss: Loss,
+                    metrics: tuple, t0: int) -> Callable:
+    """The round program of ``fit_grouped`` for one signature, unjitted:
+    the plan (its groups hold each model, local loss, noise sigma and DMS
+    flag by value, and the org positions and ids), the frozen
+    ``GALConfig``, the loss, the metrics as ``(name, callable)`` pairs and
+    the first round ``t0``. Sizes are read from the arguments' shapes;
+    the fit's data arrive as arguments only."""
+    groups = plan.groups
+    m = plan.n_orgs
+    alice_loss = lq_loss(config.alice_q)
+    masked = config.eta_stop_threshold > 0.0
+    metrics = dict(metrics)
+
     # the round program (its XLA module is ``jit_gal_rounds``)
     def gal_rounds(key, y_dev, xg_in, evals_in, res_in, sched_dev,
                    ids_dev):
         tracing.count("round_traces")     # runs only while JAX traces it
+        n, k = y_dev.shape[0], y_dev.shape[-1]
+        group_ids = [jnp.asarray(g.org_ids, jnp.uint32) for g in groups]
+        group_pos = [jnp.asarray(g.indices, jnp.int32) for g in groups]
+        inv_perm = jnp.asarray(plan.inverse_permutation, jnp.int32)
         # DMS carry: one shared (T, N, K) residual-history buffer plus each
         # DMS group's extractor stack and (M_g, T, ...) head buffers. The
         # extractor inits replicate the reference exactly: round 0's
@@ -796,59 +920,7 @@ def fit_grouped(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray,
                            restore=restore, member_sched=sched_dev,
                            org_ids=ids_dev)
 
-    with tracing.span("launch"):
-        outs, init, carry = jax.jit(gal_rounds)(
-            key0, y_in, tuple(group_x), eval_stacks, resume_in, sched_in,
-            org_ids_all)
-    state_final = carry[4]
-    dms_flags = [False] * m
-    for g in groups:
-        for i in g.indices:
-            dms_flags[i] = g.dms
-    eval_ns = [int(y_e.shape[0])
-               for (_, y_e) in (eval_sets or {}).values()]
-    rb = _resid_wire_bytes(config)
-    if sched_np is None:
-        bcast_b, gather_b = gal_round_bytes(n, k, m, eval_ns,
-                                            resid_dtype_bytes=rb)
-    else:
-        from repro.core.membership import membership_comm_ledger
-        bcast_l, gather_l = membership_comm_ledger(sched_np, n, k, eval_ns,
-                                                   resid_dtype_bytes=rb)
-        bcast_b, gather_b = bcast_l[t0:], gather_l[t0:]
-    single = len(groups) == 1 and not plan.has_dms
-    with tracing.span("finalize"):
-        out = _finalize(outs, init, masked, config.rounds - t0,
-                        dims=group_dims[0] if single else None,
-                        pad_to=group_pads[0] if single else None,
-                        comm={"comm_broadcast_bytes": bcast_b,
-                              "comm_gather_bytes": gather_b,
-                              "model_memories": gal_model_memories(
-                                  config.rounds, dms_flags,
-                                  membership=sched_np)[t0:]})
-    if sched_np is not None:
-        # executed rows only (early-stop trimmed), host bools in org order
-        out["membership"] = sched_np[t0:t0 + len(out["etas"])].tolist()
-    group_params = list(out["params"])            # tuple trimmed by _finalize
-    for gi, g in enumerate(groups):
-        if g.dms:
-            # the final carry state IS the fitted DMS ensemble: the shared
-            # extractor after the last live round plus every round's head
-            group_params[gi] = state_final[f"g{gi}"]
-    out["params"] = group_params[0] if single else None
-    out["group_params"] = group_params
-    out["group_dims"] = group_dims
-    out["group_pads"] = group_pads
-    out["plan"] = plan
-    out["mesh_devices"] = 0 if mesh is None else len(jax.devices())
-    # the final round-scan carry, verbatim: what save_artifact persists and
-    # a later fit(resume_from=...) restores. The key has been split once
-    # per scanned round (masked rounds included), so resuming continues
-    # the exact per-round draw chain of an uninterrupted longer fit.
-    out["resume"] = {"t_next": config.rounds, "f": carry[0],
-                     "f_evals": carry[1], "key": carry[2],
-                     "active": carry[3], "state": state_final}
-    return out
+    return gal_rounds
 
 
 def fit_scan(rng: jax.Array, orgs: Sequence[Any], y: jnp.ndarray, loss: Loss,

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.engine import round_program
 from repro.core.losses import CrossEntropyLoss
 from repro.core.plan import (ExecutionPlan, plan_lm_orgs, plan_mismatch,
                              plan_to_manifest)
@@ -244,6 +245,16 @@ def fit_lm(rng: jax.Array, orgs: List[LMOrganization], tokens: jnp.ndarray,
     bitwise-identical to an uninterrupted ``rounds``-round fit.
     ``store_round_params=False`` drops the per-round param stack (halves
     device memory; ``predict(rounds=t)`` then needs a re-fit).
+
+    A compiled fit reuses the round program of an earlier fit with an
+    equal signature (``repro.core.engine.round_program``): the plan (each
+    group's architecture config, org positions and ids), each group's
+    train step by identity (``make_train_step`` returns the same step for
+    equal arguments), ``local_steps``, ``use_weights``, ``use_kernel``,
+    ``eta_method``, ``eta_stop_threshold``, the resume cursor, ``rounds``
+    and ``store_round_params``. Batch, sequence and vocab sizes are read
+    from the arguments; tokens, labels and views are arguments, never
+    constants of the program.
     """
     with tracing.fit_span("fit_lm"):
         with tracing.span("plan"):
@@ -333,21 +344,10 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
     """The compiled path: every plan group's vmapped local fit inside ONE
     traced round step, scanned over rounds ``t0..rounds``; exactly one
     host sync for the whole fit."""
-    m = len(orgs)
     b, s = labels.shape
     vocab = spec["vocab"]
     xent = CrossEntropyLoss()
     groups = plan.groups
-    vsteps = [jax.vmap(orgs[g.indices[0]]._train_step,
-                       in_axes=(0, 0, {"tokens": 0, "residual": None}))
-              for g in groups]
-    cfgs = [g.model for g in groups]
-    sizes = [g.size for g in groups]
-    local_steps = spec["local_steps"]
-    use_weights, use_kernel = spec["use_weights"], spec["use_kernel"]
-    eta_method, thr = spec["eta_method"], spec["eta_stop_threshold"]
-    inv = tuple(plan.inverse_permutation)
-    permuted = inv != tuple(range(m))
 
     with tracing.span("stage"):
         y1 = jax.nn.one_hot(labels.reshape(-1), vocab)
@@ -371,10 +371,87 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
             f_init = jnp.asarray(resume["f"])
             active0 = jnp.asarray(resume["active"])
 
+    with tracing.span("launch"):
+        gal_lm_rounds = round_program(
+            _lm_rounds, plan,
+            tuple(orgs[g.indices[0]]._train_step for g in groups),
+            spec["local_steps"], spec["use_weights"], spec["use_kernel"],
+            spec["eta_method"], spec["eta_stop_threshold"], t0, rounds,
+            store_round_params)
+        params, opts, f_fin, active_fin, outs = gal_lm_rounds(
+            rng, y1, labels, tuple(views), params0, opts0, f_init, active0)
+    with tracing.span("finalize"):
+        round_params = outs.pop("params", None)
+        with tracing.span("sync"):
+            scalars = jax.device_get(outs)        # the ONE host sync
+        valid = np.asarray(scalars["valid"], bool)
+        n_exec = int(valid.sum())                 # rounds actually executed
+        tracing.count("rounds", n_exec)
+
+        for g, group in enumerate(groups):        # write back evolved state
+            for j, i in enumerate(group.indices):
+                orgs[i].params = jax.tree_util.tree_map(
+                    lambda l, j=j: l[j], params[g])
+                orgs[i].opt_state = jax.tree_util.tree_map(
+                    lambda l, j=j: l[j], opts[g])
+
+    result = GALLMResult(orgs=orgs, f0=f0, engine=label, plan=plan,
+                         fit_spec=spec)
+    result.etas = [float(e) for e in scalars["eta"][:n_exec]]
+    result.weights = [jnp.asarray(w) for w in scalars["w"][:n_exec]]
+    new_xents = [float(v) for v in scalars["xent"][:n_exec]]
+    if store_round_params:
+        new_gp = [jax.tree_util.tree_map(lambda l: l[:n_exec], gp)
+                  for gp in round_params]
+    if resume is None:
+        result.history["train_xent"] = [float(scalars["xent0"])] + new_xents
+        if store_round_params:
+            result.group_params = new_gp
+    else:
+        result.etas = resume["etas_prev"] + result.etas
+        result.weights = resume["weights_prev"] + result.weights
+        result.history = resume["hist_prev"]
+        result.history["train_xent"] = (
+            result.history["train_xent"] + new_xents)
+        if store_round_params and resume["group_params_prev"] is not None:
+            result.group_params = [
+                jax.tree_util.tree_map(
+                    lambda a, c: jnp.concatenate([a, c], axis=0), prev, new)
+                for prev, new in zip(resume["group_params_prev"], new_gp)]
+    result.resume_state = {
+        "t_next": int(rounds), "f": f_fin, "active": active_fin,
+        "params": tuple(params), "opts": tuple(opts),
+    }
+    return result
+
+
+def _lm_rounds(plan: ExecutionPlan, steps: tuple, local_steps: int,
+               use_weights: bool, use_kernel: bool, eta_method: str,
+               thr: float, t0: int, rounds: int,
+               store_round_params: bool) -> Callable:
+    """The round program of ``_fit_lm_grouped`` for one signature,
+    unjitted: the plan (each group's architecture config, org positions
+    and ids), each group's local train step by identity (it holds the
+    group's lr), the fit spec, the rounds ``t0 .. rounds`` and whether
+    the per-round params are kept. The batch, sequence and vocab sizes
+    are read from the arguments' shapes; the tokens' views, like all of
+    the fit's data, arrive as arguments."""
+    groups = plan.groups
+    m = plan.n_orgs
+    xent = CrossEntropyLoss()
+    vsteps = [jax.vmap(step, in_axes=(0, 0, {"tokens": 0, "residual": None}))
+              for step in steps]
+    cfgs = [g.model for g in groups]
+    sizes = [g.size for g in groups]
+    inv = tuple(plan.inverse_permutation)
+    permuted = inv != tuple(range(m))
+
     # the round program (its XLA module is ``jit_gal_lm_rounds``)
-    def gal_lm_rounds(key, y1_in, labels_in, params_in, opts_in, f_in,
-                      active_in):
+    def gal_lm_rounds(key, y1_in, labels_in, views_in, params_in, opts_in,
+                      f_in, active_in):
         tracing.count("round_traces")     # runs only while JAX traces it
+        b, s = labels_in.shape
+        vocab = y1_in.shape[-1]
 
         def round_step(carry, t):
             params_l, opts_l, f, active = carry
@@ -387,11 +464,11 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
                 for g in range(len(groups)):
                     p, o, _ = run_local_steps(
                         vsteps[g], params_l[g], opts_l[g],
-                        {"tokens": views[g], "residual": residual},
+                        {"tokens": views_in[g], "residual": residual},
                         local_steps)
                     pred = jax.vmap(
                         lambda pp, vv, cfg=cfgs[g]: tfm.apply(pp, cfg, vv)[0]
-                    )(p, views[g])
+                    )(p, views_in[g])
                     preds_g.append(pred.astype(jnp.float32).reshape(
                         sizes[g], b * s, vocab))
                     new_params.append(p)
@@ -439,52 +516,7 @@ def _fit_lm_grouped(rng, orgs, plan, tokens, labels, rounds, spec, label,
         outs["xent0"] = xent(y1_in, f_in)
         return params, opts, f, active, outs
 
-    with tracing.span("launch"):
-        params, opts, f_fin, active_fin, outs = jax.jit(gal_lm_rounds)(
-            rng, y1, labels, params0, opts0, f_init, active0)
-    with tracing.span("finalize"):
-        round_params = outs.pop("params", None)
-        with tracing.span("sync"):
-            scalars = jax.device_get(outs)        # the ONE host sync
-        valid = np.asarray(scalars["valid"], bool)
-        n_exec = int(valid.sum())                 # rounds actually executed
-        tracing.count("rounds", n_exec)
-
-        for g, group in enumerate(groups):        # write back evolved state
-            for j, i in enumerate(group.indices):
-                orgs[i].params = jax.tree_util.tree_map(
-                    lambda l, j=j: l[j], params[g])
-                orgs[i].opt_state = jax.tree_util.tree_map(
-                    lambda l, j=j: l[j], opts[g])
-
-    result = GALLMResult(orgs=orgs, f0=f0, engine=label, plan=plan,
-                         fit_spec=spec)
-    result.etas = [float(e) for e in scalars["eta"][:n_exec]]
-    result.weights = [jnp.asarray(w) for w in scalars["w"][:n_exec]]
-    new_xents = [float(v) for v in scalars["xent"][:n_exec]]
-    if store_round_params:
-        new_gp = [jax.tree_util.tree_map(lambda l: l[:n_exec], gp)
-                  for gp in round_params]
-    if resume is None:
-        result.history["train_xent"] = [float(scalars["xent0"])] + new_xents
-        if store_round_params:
-            result.group_params = new_gp
-    else:
-        result.etas = resume["etas_prev"] + result.etas
-        result.weights = resume["weights_prev"] + result.weights
-        result.history = resume["hist_prev"]
-        result.history["train_xent"] = (
-            result.history["train_xent"] + new_xents)
-        if store_round_params and resume["group_params_prev"] is not None:
-            result.group_params = [
-                jax.tree_util.tree_map(
-                    lambda a, c: jnp.concatenate([a, c], axis=0), prev, new)
-                for prev, new in zip(resume["group_params_prev"], new_gp)]
-    result.resume_state = {
-        "t_next": int(rounds), "f": f_fin, "active": active_fin,
-        "params": tuple(params), "opts": tuple(opts),
-    }
-    return result
+    return gal_lm_rounds
 
 
 def _fit_lm_python(rng, orgs, plan, tokens, labels, rounds,

@@ -15,6 +15,7 @@ Local losses ell_q(r, f) = mean |r - f|^q  (paper Table 4, q in {1,1.5,2,4}).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -170,9 +171,16 @@ class BCELoss(Loss):
 
 
 def lq_loss(q: float):
-    """Local regression loss ell_q(r, f) = mean |r - f|^q (paper Table 4)."""
-    q = float(q)
+    """Local regression loss ell_q(r, f) = mean |r - f|^q (paper Table 4).
 
+    Equal exponents return the same function, so a compiled round program,
+    which holds each group's local loss in its signature, is found again
+    by the next fit of freshly built organizations."""
+    return _lq_loss(float(q))
+
+
+@lru_cache(maxsize=64)
+def _lq_loss(q: float):
     def loss(r, f):
         d = jnp.abs(r - f)
         if q == 2.0:

@@ -19,7 +19,7 @@ against a seq_len KV/state cache.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, Tuple
 
 import jax
@@ -93,7 +93,21 @@ def make_train_step(cfg: ModelConfig, loss_kind: str = "gal_residual",
     -> (params, opt_state, metrics).
 
     microbatch > 1 scans gradient-accumulation slices of the global batch
-    (activation memory / microbatch; grads accumulate in f32)."""
+    (activation memory / microbatch; grads accumulate in f32).
+
+    Equal arguments return the same two objects (the factory is pure), so
+    a GAL round program, which is keyed on its steps' identity, is found
+    again by the next fit of freshly built organizations. Unhashable
+    arguments (a learning rate given as an array) build a new pair."""
+    args = (cfg, loss_kind, lr, weight_decay, flash, microbatch)
+    try:
+        hash(args)
+    except TypeError:
+        return _build_train_step(*args)
+    return _cached_train_step(*args)
+
+
+def _build_train_step(cfg, loss_kind, lr, weight_decay, flash, microbatch):
     loss_fn = LOSS_FNS[loss_kind]
     opt = adamw(lr, weight_decay=weight_decay)
 
@@ -161,6 +175,9 @@ def make_train_step(cfg: ModelConfig, loss_kind: str = "gal_residual",
         return params, opt_state, metrics
 
     return train_step, opt
+
+
+_cached_train_step = lru_cache(maxsize=64)(_build_train_step)
 
 
 def run_local_steps(train_step, params, opt_state, batch, steps: int):

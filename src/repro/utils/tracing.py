@@ -10,7 +10,11 @@
   records in its own arguments what the fit counted (``count``): the
   rounds it ran and the round programs it built (``rounds``,
   ``round_traces``), so a trace of a window holds the program's counts for
-  that window.
+  that window. A fit reuses the round program of an earlier fit with an
+  equal signature (``repro.core.engine.round_program``: the plan, the
+  config, the loss and metrics or the LM's train steps and fit spec, and
+  the rounds), so ``round_traces`` 0 on a fit's span means a reused
+  program.
 * ``scope(name)`` is a device scope ``gal.<name>`` (``jax.named_scope``):
   it names the operations traced under it in their metadata and changes no
   computation.

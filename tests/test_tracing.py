@@ -81,8 +81,11 @@ CASES = {
 
 @pytest.fixture(scope="module", params=sorted(CASES))
 def recorded(request, tmp_path_factory):
+    from repro.core import engine
     top, module, phases, make = CASES[request.param]
     fit = make()
+    # a program built before would be reused, and the spy would see no build
+    engine.clear_round_programs()
     programs = []
     real_jit = jax.jit
 
